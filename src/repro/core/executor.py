@@ -175,10 +175,11 @@ class LocalPoolExecutor(Executor):
     """`ProcessPoolExecutor`-backed stage bodies — escapes the GIL.
 
     Only stages marked ``process_safe`` (pure functions of their
-    picklable inputs: `DataStage`, `EvalStage`, user stages that opt in)
-    are dispatched to children; everything else runs inline on the
-    coordinator thread.  Marshalling ships ``(stage, picklable ctx
-    outputs, picklable params, template)`` — the same pickle surface the
+    picklable inputs: `DataStage`, user stages that opt in) are
+    dispatched to children; everything else, and every stage that does
+    device work, runs inline on the coordinator thread.  Marshalling
+    ships ``(stage, picklable ctx outputs, picklable params,
+    template)`` — the same pickle surface the
     stage cache persists — and unpicklable *inputs or outputs* fall back
     inline rather than failing the run.
 

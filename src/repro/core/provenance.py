@@ -36,6 +36,8 @@ def stable_hash(obj: Any) -> str:
 
 
 def capture_environment() -> Dict[str, Any]:
+    from repro.kernels import ops
+
     return {
         "jax_version": jax.__version__,
         "backend": jax.default_backend(),
@@ -44,7 +46,7 @@ def capture_environment() -> Dict[str, Any]:
         "python": platform.python_version(),
         "platform": platform.platform(),
         "xla_flags": os.environ.get("XLA_FLAGS", ""),
-        "kernel_backend": os.environ.get("REPRO_KERNEL_BACKEND", "ref"),
+        "kernel_backend": ops.get_backend(),
     }
 
 

@@ -356,14 +356,14 @@ class TrainStage(Stage):
         from repro.ft.elastic import state_shardings
 
         try:
-            like = jax.eval_shape(init_fn)
             mesh = placement.build_mesh()
-            shardings = state_shardings(like, model, mesh, rt_plan)
-        except Exception as e:  # placement is advisory — never block restore
+        except ValueError as e:  # no mesh of this placement fits here
             if ctx.record is not None:
                 ctx.record.log_event("reshard_skipped", {
                     "stage": self.name, "error": repr(e)})
             return None
+        shardings = state_shardings(jax.eval_shape(init_fn), model, mesh,
+                                    rt_plan)
         if ctx.record is not None:
             ctx.record.log_event("reshard", {
                 "stage": self.name, "slice": placement.slice_name,
@@ -659,11 +659,8 @@ class EvalStage(Stage):
     """Held-out loss of a trained state on freshly-seeded batches."""
 
     inputs = ("cfg", "shape")
-    # a pure function of (cfg, shape, state): eligible for process
-    # dispatch so a CPU-bound eval fan-out escapes the GIL.  The body
-    # does small jax compute — see docs/executors.md for the fork
-    # caveat; unpicklable state falls back inline automatically.
-    process_safe = True
+    # runs model.loss on the device, so it stays in the process that
+    # holds the chip (not process_safe; see docs/executors.md)
 
     def __init__(self, name: str = "eval", state_key: str = "final_state",
                  num_batches: int = 2, seed_offset: int = 10_000,

@@ -31,10 +31,7 @@ def _flash_kernel(
     k_ref,  # (1, 1, block_k, D)
     v_ref,  # (1, 1, block_k, D)
     o_ref,  # (1, 1, block_q, D)
-    m_scr,  # VMEM (block_q, 128) running max (broadcast along lanes)
-    l_scr,  # VMEM (block_q, 128) running denom
-    acc_scr,  # VMEM (block_q, D) accumulator
-    *,
+    *refs,
     scale: float,
     causal: bool,
     window: int,
@@ -44,6 +41,11 @@ def _flash_kernel(
     kv_seq: int,
     num_kv_blocks: int,
 ):
+    # refs: [lse_ref (1, 1, block_q, 128) when requested], then VMEM
+    # scratch m (block_q, 128) running max and l (block_q, 128) running
+    # denom, both broadcast along lanes, and acc (block_q, D)
+    lse_ref = refs[0] if len(refs) == 4 else None
+    m_scr, l_scr, acc_scr = refs[-3:]
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -103,13 +105,15 @@ def _flash_kernel(
     def _finalize():
         denom = jnp.maximum(l_scr[:, 0], 1e-30)
         o_ref[0, 0] = (acc_scr[...] / denom[:, None]).astype(o_ref.dtype)
+        if lse_ref is not None:
+            lse_ref[0, 0] = m_scr[...] + jnp.log(jnp.maximum(l_scr[...], 1e-30))
 
 
 @functools.partial(
     jax.jit,
     static_argnames=(
         "kv_seq", "scale", "causal", "window", "q_offset", "block_q",
-        "block_k", "interpret",
+        "block_k", "return_lse", "interpret",
     ),
 )
 def flash_attention_bhsd(
@@ -124,8 +128,12 @@ def flash_attention_bhsd(
     q_offset: int = 0,
     block_q: int = 256,
     block_k: int = 256,
+    return_lse: bool = False,
     interpret: bool = False,
-) -> jax.Array:
+):
+    """Returns the attention output, or ``(out, lse)`` with
+    ``return_lse``: the per-row log-sum-exp of the scaled scores,
+    ``(B, H, S)`` float32, which the backward pass needs."""
     B, H, S, D = q.shape
     KH, T = k.shape[1], k.shape[2]
     group = H // KH
@@ -143,7 +151,15 @@ def flash_attention_bhsd(
         kv_seq=kv_seq,
         num_kv_blocks=nk,
     )
-    return pl.pallas_call(
+    out_specs = pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0))
+    out_shape = jax.ShapeDtypeStruct((B, H, S, D), q.dtype)
+    if return_lse:
+        # lane-broadcast like the m/l scratch: a (block_q, 128) tile
+        out_specs = [out_specs, pl.BlockSpec((1, 1, block_q, 128),
+                                             lambda b, h, i, j: (b, h, i, 0))]
+        out_shape = [out_shape,
+                     jax.ShapeDtypeStruct((B, H, S, 128), jnp.float32)]
+    res = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -151,8 +167,8 @@ def flash_attention_bhsd(
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
             pl.BlockSpec((1, 1, block_k, D), lambda b, h, i, j, g=group: (b, h // g, j, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q, D), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, D), q.dtype),
+        out_specs=out_specs,
+        out_shape=out_shape,
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, 128), jnp.float32),
@@ -160,3 +176,7 @@ def flash_attention_bhsd(
         ],
         interpret=interpret,
     )(q, k, v)
+    if return_lse:
+        out, lse = res
+        return out, lse[..., 0]
+    return res

@@ -35,11 +35,14 @@ def _mlstm_kernel(
     q_ref,  # (1, 1, L, D)
     k_ref,
     v_ref,  # (1, 1, L, DV)
-    i_ref,  # (1, 1, L)
-    f_ref,  # (1, 1, L)
+    cumf_c_ref,  # (1, 1, L, 1) chunk-local inclusive cumsum of log f
+    lmax_c_ref,  # (1, 1, L, 1) cummax_{j<=t}(log i_j - cumf_j) + cumf_t
+    logi_c_ref,  # (1, 1, L, 1) log i
+    cumf_r_ref,  # (1, 1, 1, L) cumf as a row
+    logi_r_ref,  # (1, 1, 1, L) log i as a row
     h_ref,  # out (1, 1, L, DV)
     C_scr,  # VMEM (D, DV) f32
-    n_scr,  # VMEM (1, D) f32  (kept 2-D for TPU layout)
+    n_scr,  # VMEM (1, D) f32
     m_scr,  # VMEM (1, 128) f32
     *,
     scale: float,
@@ -57,29 +60,29 @@ def _mlstm_kernel(
     q = q_ref[0, 0].astype(jnp.float32) * scale  # (L, D)
     k = k_ref[0, 0].astype(jnp.float32)  # (L, D)
     v = v_ref[0, 0].astype(jnp.float32)  # (L, DV)
-    log_i = i_ref[0, 0].astype(jnp.float32)  # (L,)
-    log_f = jax.nn.log_sigmoid(f_ref[0, 0].astype(jnp.float32))  # (L,)
+    cumf_c = cumf_c_ref[0, 0]  # (L, 1)
+    logi_c = logi_c_ref[0, 0]
+    cumf_r = cumf_r_ref[0, 0]  # (1, L)
+    logi_r = logi_r_ref[0, 0]
 
-    m_prev = m_scr[0, 0]
+    m_prev = m_scr[:, 0:1]  # (1, 1)
     C_prev = C_scr[...]
-    n_prev = n_scr[0, :]
+    n_prev = n_scr[...]  # (1, D)
 
-    cumf = jnp.cumsum(log_f)  # (L,) inclusive: sum_{j<=t} log f_j
-    # a_j = log i_j - cumf_j ; local stabilizer: running max over j<=t
-    a = log_i - cumf
-    local_max = jax.lax.cummax(a) + cumf  # (L,)
-    m_t = jnp.maximum(m_prev + cumf, local_max)  # (L,)
+    m_t = jnp.maximum(m_prev + cumf_c, lmax_c_ref[0, 0])  # (L, 1)
 
     # ---- inter-chunk contribution -------------------------------------
-    inter_w = jnp.exp(m_prev + cumf - m_t)  # (L,)
+    inter_w = jnp.exp(m_prev + cumf_c - m_t)  # (L, 1)
     h_inter = jax.lax.dot_general(
         q, C_prev, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
-    ) * inter_w[:, None]  # (L, DV)
-    qn_inter = (q @ n_prev) * inter_w  # (L,)
+    ) * inter_w  # (L, DV)
+    qn_inter = jax.lax.dot_general(
+        q, n_prev, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * inter_w  # (L, 1)
 
     # ---- intra-chunk contribution (masked attention-like) -------------
     # W[t, j] = exp(cumf_t - cumf_j + log_i_j - m_t) for j <= t
-    logw = cumf[:, None] - cumf[None, :] + log_i[None, :] - m_t[:, None]
+    logw = cumf_c - cumf_r + logi_r - m_t  # (L, L)
     tidx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 0)
     jidx = jax.lax.broadcasted_iota(jnp.int32, (L, L), 1)
     w = jnp.where(tidx >= jidx, jnp.exp(logw), 0.0)  # (L, L)
@@ -89,26 +92,24 @@ def _mlstm_kernel(
     h_intra = jax.lax.dot_general(
         s, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )
-    qn_intra = jnp.sum(s, axis=-1)  # (L,)
+    qn_intra = jnp.sum(s, axis=-1, keepdims=True)  # (L, 1)
 
     denom = jnp.maximum(jnp.abs(qn_inter + qn_intra), jnp.exp(-m_t))
-    h = (h_inter + h_intra) / denom[:, None]
-    h_ref[0, 0] = h.astype(h_ref.dtype)
+    h_ref[0, 0] = ((h_inter + h_intra) / denom).astype(h_ref.dtype)
 
     # ---- carry update ---------------------------------------------------
-    m_end = m_t[L - 1]
+    m_end = m_t[L - 1:L, :]  # (1, 1)
+    cumf_end = cumf_c[L - 1:L, :]
     # decay of old state across the whole chunk
-    c_decay = jnp.exp(m_prev + cumf[L - 1] - m_end)
+    c_decay = jnp.exp(m_prev + cumf_end - m_end)
     # per-step weights into the end-of-chunk state
-    wk = jnp.exp(cumf[L - 1] - cumf + log_i - m_end)  # (L,)
-    kw = k * wk[:, None]  # (L, D)
-    C_new = c_decay * C_prev + jax.lax.dot_general(
+    wk = jnp.exp(cumf_end - cumf_c + logi_c - m_end)  # (L, 1)
+    kw = k * wk  # (L, D)
+    C_scr[...] = c_decay * C_prev + jax.lax.dot_general(
         kw, v, (((0,), (0,)), ((), ())), preferred_element_type=jnp.float32
     )  # (D, DV)
-    n_new = c_decay * n_prev + jnp.sum(kw, axis=0)
-    C_scr[...] = C_new
-    n_scr[0, :] = n_new
-    m_scr[...] = jnp.full_like(m_scr, m_end)
+    n_scr[...] = c_decay * n_prev + jnp.sum(kw, axis=0, keepdims=True)
+    m_scr[...] = jnp.broadcast_to(m_end, m_scr.shape)
 
 
 @functools.partial(
@@ -127,17 +128,27 @@ def mlstm_scan_bhsd(
     B, H, S, D = q.shape
     DV = v.shape[-1]
     assert S % chunk == 0, (S, chunk)
-    grid = (B, H, S // chunk)
+    nc = S // chunk
+    # Gate prefix terms are chunk-local scans over the time axis: cheap
+    # in XLA, and the kernel then needs them only as (L, 1) columns and
+    # (1, L) rows, which keeps every block within the TPU's 8x128 tiling.
+    log_i = i_pre.astype(jnp.float32).reshape(B, H, nc, chunk)
+    log_f = jax.nn.log_sigmoid(f_pre.astype(jnp.float32)).reshape(B, H, nc, chunk)
+    cumf = jnp.cumsum(log_f, axis=-1)
+    lmax = jax.lax.cummax(log_i - cumf, axis=3) + cumf
+    col = lambda x: x.reshape(B, H, S, 1)
+    row = lambda x: x.reshape(B, H, 1, S)
+    col_spec = pl.BlockSpec((1, 1, chunk, 1), lambda b, h, c: (b, h, c, 0))
+    row_spec = pl.BlockSpec((1, 1, 1, chunk), lambda b, h, c: (b, h, 0, c))
     kernel = functools.partial(_mlstm_kernel, scale=D ** -0.5, chunk=chunk)
     return pl.pallas_call(
         kernel,
-        grid=grid,
+        grid=(B, H, nc),
         in_specs=[
             pl.BlockSpec((1, 1, chunk, D), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, D), lambda b, h, c: (b, h, c, 0)),
             pl.BlockSpec((1, 1, chunk, DV), lambda b, h, c: (b, h, c, 0)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
-            pl.BlockSpec((1, 1, chunk), lambda b, h, c: (b, h, c)),
+            col_spec, col_spec, col_spec, row_spec, row_spec,
         ],
         out_specs=pl.BlockSpec((1, 1, chunk, DV), lambda b, h, c: (b, h, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, H, S, DV), v.dtype),
@@ -147,4 +158,4 @@ def mlstm_scan_bhsd(
             pltpu.VMEM((1, 128), jnp.float32),
         ],
         interpret=interpret,
-    )(q, k, v, i_pre, f_pre)
+    )(q, k, v, col(cumf), col(lmax), col(log_i), row(cumf), row(log_i))

@@ -1,11 +1,18 @@
-"""jit'd public wrappers around the Pallas kernels with oracle fallback.
+"""jit'd public wrappers around the Pallas kernels and their oracles.
 
-Backend selection (``set_backend`` / env ``REPRO_KERNEL_BACKEND``):
+Backend (resolved from the device on first use, see :func:`get_backend`):
 
-  * ``ref``       — pure-jnp oracle (default: CPU container, dry-run lowering)
+  * ``tpu``       — compiled Pallas; chosen when ``jax.default_backend()``
+                    is ``"tpu"``
+  * ``ref``       — pure-jnp oracle / XLA paths; chosen on every other
+                    backend (CPU, dry-run lowering)
   * ``interpret`` — Pallas kernels executed with ``interpret=True`` (CPU
-                    correctness validation of the TPU kernel bodies)
-  * ``tpu``       — compiled Pallas (the deployment target)
+                    correctness validation of the TPU kernel bodies); only
+                    ever set explicitly by tests through :func:`set_backend`
+
+On ``tpu``/``interpret`` a kernel never gives way to its oracle:
+``flash_attention`` differentiates through a custom VJP, and the kernels
+without a backward raise under ``jax.grad``.
 
 Wrappers own all layout plumbing (BSHD↔BHSD transposes, lane padding to
 128, block padding) so both kernel and oracle see hardware-friendly
@@ -13,6 +20,7 @@ shapes.
 """
 from __future__ import annotations
 
+import functools
 import os
 from typing import Optional, Tuple
 
@@ -20,12 +28,13 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ref
+from repro.parallel import hints
 from repro.kernels.flash_attention import flash_attention_bhsd
 from repro.kernels.mlstm_scan import mlstm_scan_bhsd
 from repro.kernels.ssm_scan import ssm_scan_bsd
 from repro.kernels.moe_gmm import moe_gmm_sorted
 
-_BACKEND = os.environ.get("REPRO_KERNEL_BACKEND", "ref")
+_BACKEND: Optional[str] = None  # resolved lazily by get_backend()
 _VALID = ("ref", "interpret", "tpu")
 _ATTN_IMPL = os.environ.get("REPRO_ATTN_IMPL", "xla")  # xla | tri
 _SSM_CHUNK = 0  # 0 = per-step oracle scan; >0 = chunked fallback
@@ -61,7 +70,31 @@ def set_backend(name: str) -> None:
 
 
 def get_backend() -> str:
+    """The kernel backend in use: ``tpu`` on a TPU, ``ref`` elsewhere,
+    unless :func:`set_backend` chose one.  Resolved on first use (not at
+    import), so importing this module never initializes a device."""
+    global _BACKEND
+    if _BACKEND is None:
+        _BACKEND = "tpu" if jax.default_backend() == "tpu" else "ref"
     return _BACKEND
+
+
+def _forward_only(name: str, fn):
+    """Wrap a Pallas kernel call that has no backward: the primal runs
+    unchanged, and differentiating it raises instead of silently
+    differentiating something else."""
+
+    @jax.custom_vjp
+    def op(*args):
+        return fn(*args)
+
+    def fwd(*args):
+        raise NotImplementedError(
+            f"{name}: the Pallas kernel (backend {get_backend()!r}) has no "
+            f"backward; it cannot be differentiated")
+
+    op.defvjp(fwd, lambda res, g: None)
+    return op
 
 
 def _pad_to(x: jax.Array, axis: int, mult: int) -> Tuple[jax.Array, int]:
@@ -86,7 +119,16 @@ def flash_attention(
     block_q: int = 256,
     block_k: int = 256,
 ) -> jax.Array:
-    if _BACKEND == "ref":
+    """Attention over a whole sequence (train step, prefill).
+
+    ``ref``: the oracle for small S·T, else the XLA flash scan.
+    ``tpu``/``interpret``: the Pallas kernel under a ``jax.custom_vjp``
+    whose forward is ``flash_attention_bhsd`` and whose backward is the
+    XLA flash backward of ``flash_xla.flash_attention_xla``, fed the
+    kernel's (out, lse) residuals.  Under an installed mesh the kernel
+    runs per data shard (``hints.shard_batch``)."""
+    backend = get_backend()
+    if backend == "ref":
         S, T = q.shape[1], k.shape[1]
         if S * T <= 1024 * 1024:
             return ref.attention(q, k, v, causal=causal, window=window,
@@ -101,12 +143,20 @@ def flash_attention(
         return flash_attention_xla(q, k, v, causal, window, q_offset,
                                    _FLASH_BQ, _FLASH_BK)
 
-    B, S, H, D = q.shape
-    T = k.shape[1]
-    scale = D ** -0.5
-    bq = block_q if S >= block_q else S
-    bk = block_k if T >= block_k else T
+    bq = block_q if q.shape[1] >= block_q else q.shape[1]
+    bk = block_k if k.shape[1] >= block_k else k.shape[1]
+    return hints.shard_batch(
+        lambda q, k, v: _flash_pallas(q, k, v, causal, window, q_offset, bq,
+                                      bk, backend == "interpret"))(q, k, v)
 
+
+def _flash_pallas_call(q, k, v, causal, window, q_offset, bq, bk, interpret,
+                       return_lse=False):
+    """Pallas forward in the (B, S, H, D) layout.  With ``return_lse``
+    returns (out, lse), ``lse`` (B, S, KH, G) float32 as ``flash_xla``
+    lays it out."""
+    B, S, H, D = q.shape
+    KH = k.shape[2]
     qt = jnp.swapaxes(q, 1, 2)  # (B, H, S, D)
     kt = jnp.swapaxes(k, 1, 2)
     vt = jnp.swapaxes(v, 1, 2)
@@ -117,14 +167,42 @@ def flash_attention(
     kt, t_orig = _pad_to(kt, 2, bk)
     vt, _ = _pad_to(vt, 2, bk)
 
-    out = flash_attention_bhsd(
+    res = flash_attention_bhsd(
         qt, kt, vt,
-        kv_seq=t_orig, scale=scale, causal=causal, window=window,
-        q_offset=q_offset, block_q=bq, block_k=bk,
-        interpret=(_BACKEND == "interpret"),
+        kv_seq=t_orig, scale=D ** -0.5, causal=causal, window=window,
+        q_offset=q_offset, block_q=bq, block_k=bk, return_lse=return_lse,
+        interpret=interpret,
     )
-    out = out[:, :, :s_orig, :D]
-    return jnp.swapaxes(out, 1, 2)
+    if not return_lse:
+        return jnp.swapaxes(res[:, :, :s_orig, :D], 1, 2)
+    out, lse = res
+    out = jnp.swapaxes(out[:, :, :s_orig, :D], 1, 2)
+    lse = jnp.swapaxes(lse[:, :, :s_orig], 1, 2).reshape(B, S, KH, H // KH)
+    return out, lse
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash_pallas(q, k, v, causal, window, q_offset, bq, bk, interpret):
+    return _flash_pallas_call(q, k, v, causal, window, q_offset, bq, bk,
+                              interpret)
+
+
+def _flash_pallas_vjp_fwd(q, k, v, causal, window, q_offset, bq, bk,
+                          interpret):
+    out, lse = _flash_pallas_call(q, k, v, causal, window, q_offset, bq, bk,
+                                  interpret, return_lse=True)
+    return out, (q, k, v, out, lse)
+
+
+def _flash_pallas_vjp_bwd(causal, window, q_offset, bq, bk, interpret, res,
+                          do):
+    from repro.kernels.flash_xla import _bwd_vjp
+
+    # blocked by set_flash_blocks, like the ref backend's XLA flash
+    return _bwd_vjp(causal, window, q_offset, _FLASH_BQ, _FLASH_BK, res, do)
+
+
+_flash_pallas.defvjp(_flash_pallas_vjp_fwd, _flash_pallas_vjp_bwd)
 
 
 def decode_attention(
@@ -180,7 +258,8 @@ def paged_decode_attention_mq(
     B, T, H, D = q.shape
     KH, _, page, _ = k_pool.shape
     max_pages = page_table.shape[1]
-    if _BACKEND == "ref":
+    backend = get_backend()
+    if backend == "ref":
         if B * max_pages * page <= 256 * 1024:
             return ref.paged_attention_mq(q, k_pool, v_pool, page_table,
                                           base_len)
@@ -200,7 +279,7 @@ def paged_decode_attention_mq(
     out = paged_attention_mq_bkgd(
         qt, kp, vp, page_table, base_len,
         scale=D ** -0.5, page=page, group=G,
-        interpret=(_BACKEND == "interpret"),
+        interpret=(backend == "interpret"),
     )
     out = out[..., :D].reshape(B, KH, T, G, D).transpose(0, 2, 1, 3, 4)
     return out.reshape(B, T, H, D)
@@ -224,7 +303,8 @@ def paged_decode_attention(
     B, _, H, D = q.shape
     KH, _, page, _ = k_pool.shape
     max_pages = page_table.shape[1]
-    if _BACKEND == "ref":
+    backend = get_backend()
+    if backend == "ref":
         if B * max_pages * page <= 256 * 1024:
             return ref.paged_attention(q, k_pool, v_pool, page_table, kv_len)
         from repro.kernels.flash_xla import paged_attention_xla
@@ -241,7 +321,7 @@ def paged_decode_attention(
     out = paged_attention_bkgd(
         qt, kp, vp, page_table, kv_len,
         scale=D ** -0.5, page=page,
-        interpret=(_BACKEND == "interpret"),
+        interpret=(backend == "interpret"),
     )
     return out[..., :D].reshape(B, 1, H, D)
 
@@ -256,7 +336,8 @@ def mlstm_scan(
     *,
     chunk: int = 256,
 ) -> jax.Array:
-    if _BACKEND == "ref":
+    backend = get_backend()
+    if backend == "ref":
         h, _ = ref.mlstm_scan(q, k, v, i_pre, f_pre)
         return h
     S = q.shape[2]
@@ -271,7 +352,9 @@ def mlstm_scan(
         fp = jnp.pad(f_pre, ((0, 0), (0, 0), (0, pad)), constant_values=30.0)
     else:
         ip, fp = i_pre, f_pre
-    h = mlstm_scan_bhsd(qp, kp, vp, ip, fp, chunk=c, interpret=(_BACKEND == "interpret"))
+    kernel = functools.partial(mlstm_scan_bhsd, chunk=c,
+                               interpret=(backend == "interpret"))
+    h = _forward_only("mlstm_scan", kernel)(qp, kp, vp, ip, fp)
     return h[:, :, :s_orig]
 
 
@@ -300,7 +383,8 @@ def ssm_scan(
     block_d: int = 256,
     chunk: int = 128,
 ) -> jax.Array:
-    if _BACKEND == "ref":
+    backend = get_backend()
+    if backend == "ref":
         if _SSM_CHUNK > 0:
             from repro.kernels.ssm_vjp import ssm_scan_ckpt
 
@@ -309,7 +393,7 @@ def ssm_scan(
         return y
     Bsz, S, Din = x.shape
     bd = min(block_d, Din)
-    c = min(chunk, S)
+    c = min(chunk, -(-S // 8) * 8)  # the kernel moves 8 steps at a time
     xp, d_orig = _pad_to(x, 2, bd)
     dtp, _ = _pad_to(dt, 2, bd)
     Ap, _ = _pad_to(A, 0, bd)
@@ -318,10 +402,9 @@ def ssm_scan(
     Bp, _ = _pad_to(Bmat, 1, c)
     Cp, _ = _pad_to(Cmat, 1, c)
     Dp, _ = _pad_to(D, 0, bd)
-    y = ssm_scan_bsd(
-        xp, dtp, Ap, Bp, Cp, Dp,
-        block_d=bd, chunk=c, interpret=(_BACKEND == "interpret"),
-    )
+    kernel = functools.partial(ssm_scan_bsd, block_d=bd, chunk=c,
+                               interpret=(backend == "interpret"))
+    y = _forward_only("ssm_scan", kernel)(xp, dtp, Ap, Bp, Cp, Dp)
     return y[:, :s_orig, :d_orig]
 
 
@@ -349,10 +432,11 @@ def moe_gmm(
     *,
     block_m: int = 256,
 ) -> jax.Array:
-    if _BACKEND == "ref":
+    backend = get_backend()
+    if backend == "ref":
         return ref.moe_gmm(tokens, group_sizes, w)
     tp, m_orig = _pad_to(tokens, 0, block_m)
-    out = moe_gmm_sorted(
-        tp, group_sizes, w, block_m=block_m, interpret=(_BACKEND == "interpret")
-    )
+    kernel = functools.partial(moe_gmm_sorted, block_m=block_m,
+                               interpret=(backend == "interpret"))
+    out = _forward_only("moe_gmm", kernel)(tp, group_sizes, w)
     return out[:m_orig]

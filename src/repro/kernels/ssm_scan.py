@@ -7,7 +7,8 @@ The time recurrence is sequential; channels are embarrassingly parallel.
 TPU adaptation: tile the channel dimension across the grid (each grid row
 owns a (block_d, N) state slab resident in VMEM) and walk the sequence in
 chunks along the innermost (sequential) grid axis, with an inner
-``fori_loop`` over the chunk's timesteps.  All per-step work is VPU
+``fori_loop`` over the chunk's timesteps, eight at a time (one aligned
+sublane tile per load and store).  All per-step work is VPU
 elementwise + a tiny (block_d × N) reduction — the kernel exists to keep
 the state in VMEM across the whole sequence instead of bouncing it to HBM
 every step (the XLA scan fallback does exactly that bounce).
@@ -22,6 +23,9 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+
+ROWS = 8  # timesteps per aligned load/store: one sublane tile
 
 
 def _ssm_kernel(
@@ -42,21 +46,28 @@ def _ssm_kernel(
         h_scr[...] = jnp.zeros_like(h_scr)
 
     A = A_ref[...].astype(jnp.float32)  # (bd, N)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (A.shape[0], ROWS), 1)
 
-    def step(t, _):
-        xt = x_ref[0, t, :].astype(jnp.float32)  # (bd,)
-        dtt = dt_ref[0, t, :].astype(jnp.float32)  # (bd,)
-        bt = B_ref[0, t, :].astype(jnp.float32)  # (N,)
-        ct = C_ref[0, t, :].astype(jnp.float32)  # (N,)
-        h = h_scr[...]
-        decay = jnp.exp(dtt[:, None] * A)  # (bd, N)
-        h = decay * h + (dtt * xt)[:, None] * bt[None, :]
-        h_scr[...] = h
-        y = jnp.sum(h * ct[None, :], axis=-1)  # (bd,)
-        y_ref[0, t, :] = y.astype(y_ref.dtype)
-        return 0
+    def group(g, h):
+        # Reads and writes move ROWS timesteps at a sublane-aligned
+        # offset; the steps inside the group are unrolled.  Channels go
+        # to sublanes ((bd, ROWS) after the transpose) so each step's
+        # (bd, 1) column broadcasts against the (bd, N) state.
+        t0 = pl.multiple_of(g * ROWS, ROWS)
+        xs = x_ref[0, pl.ds(t0, ROWS), :].astype(jnp.float32).T  # (bd, ROWS)
+        dts = dt_ref[0, pl.ds(t0, ROWS), :].astype(jnp.float32).T
+        bs = B_ref[0, pl.ds(t0, ROWS), :].astype(jnp.float32)  # (ROWS, N)
+        cs = C_ref[0, pl.ds(t0, ROWS), :].astype(jnp.float32)
+        ys = jnp.zeros(lane.shape, jnp.float32)
+        for j in range(ROWS):
+            dtj = dts[:, j:j + 1]  # (bd, 1)
+            h = jnp.exp(dtj * A) * h + (dtj * xs[:, j:j + 1]) * bs[j:j + 1, :]
+            yj = jnp.sum(h * cs[j:j + 1, :], axis=-1, keepdims=True)  # (bd, 1)
+            ys = jnp.where(lane == j, yj, ys)
+        y_ref[0, pl.ds(t0, ROWS), :] = ys.T.astype(y_ref.dtype)
+        return h
 
-    jax.lax.fori_loop(0, chunk, step, 0)
+    h_scr[...] = jax.lax.fori_loop(0, chunk // ROWS, group, h_scr[...])
 
 
 @functools.partial(jax.jit, static_argnames=("block_d", "chunk", "interpret"))
@@ -74,7 +85,8 @@ def ssm_scan_bsd(
 ) -> jax.Array:
     Bsz, S, Din = x.shape
     N = A.shape[-1]
-    assert Din % block_d == 0 and S % chunk == 0, (Din, block_d, S, chunk)
+    assert Din % block_d == 0 and S % chunk == 0 and chunk % ROWS == 0, (
+        Din, block_d, S, chunk)
     grid = (Bsz, Din // block_d, S // chunk)
     kernel = functools.partial(_ssm_kernel, chunk=chunk)
     y = pl.pallas_call(

@@ -9,7 +9,7 @@ from __future__ import annotations
 
 import dataclasses
 import re
-from typing import Any, Dict, Optional, Tuple
+from typing import Any, Dict, Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -52,13 +52,17 @@ class LoweredCell:
     fn: Any  # the jitted function (un-lowered)
     args: Tuple  # ShapeDtypeStruct args to lower with
     plan: Plan
+    in_shardings: Tuple  # where fn expects each arg (to place real arrays)
 
 
-def build_cell(arch: str, shape_name: str, mesh: Mesh,
+def build_cell(arch: str, shape: Union[str, ShapeConfig], mesh: Mesh,
                plan: Optional[Plan] = None,
                opt_cfg: Optional[OptimizerConfig] = None) -> LoweredCell:
+    """``shape`` is a name from ``configs.SHAPES`` or a ShapeConfig."""
     cfg = get_config(arch)
-    shape = get_shape(shape_name)
+    if isinstance(shape, str):
+        shape = get_shape(shape)
+    shape_name = shape.name
     ok, why = shape_applicable(cfg, shape)
     if not ok:
         raise ValueError(f"{arch} × {shape_name}: {why}")
@@ -75,7 +79,8 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh,
             out_shardings=(art.state_shardings, None),
         )
         return LoweredCell(arch, shape_name, mesh_desc, "train", fn,
-                           (art.state_specs, art.batch_input_specs), plan)
+                           (art.state_specs, art.batch_input_specs), plan,
+                           (art.state_shardings, art.batch_shardings))
 
     # serving paths use bf16 parameters
     from repro.parallel import hints as act_hints
@@ -120,7 +125,7 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh,
         fn = jax.jit(prefill_fn, in_shardings=(p_shard, b_shard),
                      out_shardings=(None, cache_shard))
         return LoweredCell(arch, shape_name, mesh_desc, "prefill", fn,
-                           (p_specs, b_specs), plan)
+                           (p_specs, b_specs), plan, (p_shard, b_shard))
 
     # decode
     specs = model.input_specs(shape)
@@ -136,7 +141,8 @@ def build_cell(arch: str, shape_name: str, mesh: Mesh,
     fn = jax.jit(decode_fn, in_shardings=(p_shard, cache_shard, tok_shard),
                  out_shardings=(None, cache_shard))
     return LoweredCell(arch, shape_name, mesh_desc, "decode", fn,
-                       (p_specs, cache_spec, tok_spec), plan)
+                       (p_specs, cache_spec, tok_spec), plan,
+                       (p_shard, cache_shard, tok_shard))
 
 
 # ===========================================================================

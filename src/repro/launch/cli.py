@@ -770,6 +770,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 def main() -> None:
     args = build_parser().parse_args()
+    from repro.launch import compile_cache
+
+    compile_cache.enable()
     args.fn(args)
 
 

@@ -17,23 +17,32 @@
 
     # ... or draft with a smaller same-vocab model
     PYTHONPATH=src python -m repro.launch.serve --spec-k 4 --draft qwen1.5-4b
+
+    # the published config (default: the reduced smoke config)
+    PYTHONPATH=src python -m repro.launch.serve --full --engine paged
 """
 from __future__ import annotations
 
 import argparse
 import time
+from typing import List, Optional
 
 import jax
 import numpy as np
 
 from repro.configs import get_config, reduced
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.serve import Request, ServeEngine
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> None:
+    """Serve a synthetic request burst and print its throughput."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
+    ap.add_argument("--full", action="store_true",
+                    help="serve the published config (and --draft's) "
+                         "instead of the reduced one")
     ap.add_argument("--requests", type=int, default=16)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -55,14 +64,16 @@ def main() -> None:
     ap.add_argument("--draft", default="",
                     help="draft model arch name (same vocab); empty = "
                          "n-gram proposer")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    compile_cache.enable()
 
-    cfg = reduced(get_config(args.arch))
+    scale = (lambda c: c) if args.full else reduced
+    cfg = scale(get_config(args.arch))
     model = build_model(cfg)
     params, _ = model.init(jax.random.PRNGKey(args.seed))
     draft = dparams = None
     if args.draft:
-        dcfg = reduced(get_config(args.draft))
+        dcfg = scale(get_config(args.draft))
         draft = build_model(dcfg)
         dparams, _ = draft.init(jax.random.PRNGKey(args.seed + 1))
     engine = ServeEngine(model, params, max_batch=args.max_batch,

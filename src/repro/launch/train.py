@@ -3,6 +3,10 @@
     PYTHONPATH=src python -m repro.launch.train --arch qwen2-1.5b \
         --steps 200 --batch 8 --seq 128 --reduced
 
+    # published widths, depth cut to 4 layers
+    PYTHONPATH=src python -m repro.launch.train --full --layers 4 \
+        --steps 3 --batch 4 --seq 1024
+
 On the CPU container this drives reduced/small configs for real; on a
 fleet the same driver runs full configs (the mesh/plan come from the
 planner either way).  The loop runs inside the execution envelope:
@@ -13,6 +17,7 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import time
+from typing import Any, Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -24,6 +29,7 @@ from repro.core.provenance import ProvenanceStore
 from repro.checkpoint import Checkpointer
 from repro.data import DataConfig, make_stream
 from repro.ft.failures import FailureSchedule
+from repro.launch import compile_cache
 from repro.models import build_model
 from repro.parallel.sharding import Plan
 from repro.train import (
@@ -34,7 +40,8 @@ from repro.train import (
 )
 
 
-def main() -> None:
+def main(argv: Optional[List[str]] = None) -> Dict[str, Any]:
+    """Train; returns ``{"run_id", "losses", "n_params", "wall_s"}``."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-1.5b")
     ap.add_argument("--steps", type=int, default=100)
@@ -44,8 +51,11 @@ def main() -> None:
     ap.add_argument("--reduced", action="store_true", default=True)
     ap.add_argument("--full", dest="reduced", action="store_false")
     ap.add_argument("--width", type=int, default=0,
-                    help="override d_model for mid-size runs (e.g. ~100M)")
-    ap.add_argument("--layers", type=int, default=0)
+                    help="override d_model for mid-size runs (e.g. ~100M; "
+                         "reduced configs only)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="cut depth to this many layers (widths unchanged "
+                         "with --full)")
     ap.add_argument("--remat", default="none")
     ap.add_argument("--microbatch", type=int, default=1)
     ap.add_argument("--ckpt-every", type=int, default=50)
@@ -56,9 +66,14 @@ def main() -> None:
     ap.add_argument("--no-donate", action="store_true",
                     help="disable train-state buffer donation (donation "
                          "updates the state in place; no-op on CPU)")
-    args = ap.parse_args()
+    args = ap.parse_args(argv)
+    if args.width and not args.reduced:
+        ap.error("--width cuts widths; --full keeps the published widths")
+    compile_cache.enable()
 
     cfg = get_config(args.arch)
+    if not args.reduced and args.layers:
+        cfg = dataclasses.replace(cfg, num_layers=args.layers)
     if args.reduced:
         over = {}
         if args.width:
@@ -119,6 +134,8 @@ def main() -> None:
     print(f"params={n_params/1e6:.1f}M steps={len(losses)} "
           f"loss {losses[0]:.4f} -> {losses[-1]:.4f} "
           f"wall={dt:.1f}s ({tok_s:,.0f} tok/s) restarts={env.restarts}")
+    return {"run_id": record.run_id, "losses": losses, "n_params": n_params,
+            "wall_s": dt}
 
 
 if __name__ == "__main__":

@@ -145,7 +145,6 @@ def apply_moe_shardmap(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, ja
     The local expert compute `(E_loc, C·m, D) × (E_loc, D, F)` is exactly
     the layout `kernels/moe_gmm.py` serves on TPU.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = _moe_mesh
@@ -213,12 +212,12 @@ def apply_moe_shardmap(p, x: jax.Array, cfg: ModelConfig) -> Tuple[jax.Array, ja
         return out.reshape(b_loc, s_loc, D), aux
 
     bspec = P(dp or None, "model", None)  # batch over data, seq over model
-    out, aux = shard_map(
+    out, aux = jax.shard_map(
         local_fn,
         mesh=mesh,
         in_specs=(bspec, P(None, None), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(bspec, P()),
-        check_rep=False,
+        check_vma=False,
     )(x, p["router"], p["moe_wg"], p["moe_wu"], p["moe_wd"])
     return out, aux.astype(jnp.float32)

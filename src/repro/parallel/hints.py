@@ -13,7 +13,7 @@ model axis) keeps propagation honest everywhere in between.
 """
 from __future__ import annotations
 
-from typing import Callable, Optional
+from typing import Callable, Optional, Tuple
 
 import jax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
@@ -22,6 +22,7 @@ _ACT: Optional[Callable] = None
 _LOGITS: Optional[Callable] = None
 _ATTN_Q: Optional[Callable] = None
 _PIN: Optional[Callable] = None
+_MESH: Optional[Tuple[Mesh, Optional[Tuple[str, ...]]]] = None  # (mesh, dp)
 
 
 def act(x):
@@ -48,9 +49,29 @@ def attn_q(x):
     return _ATTN_Q(x) if _ATTN_Q is not None else x
 
 
+def shard_batch(fn: Callable) -> Callable:
+    """``fn`` run per data shard when a mesh is installed.
+
+    GSPMD cannot partition a Pallas (Mosaic) kernel, so a kernel call
+    whose arguments and result all lead with the batch dimension runs
+    under ``shard_map``: batch split over the dp axes when it divides
+    them, else replicated.  Identity without an installed mesh."""
+    if _MESH is None:
+        return fn
+    mesh, dp = _MESH
+
+    def per_shard(*args):
+        split = dp and args[0].shape[0] % _size(mesh, dp) == 0
+        spec = P(dp) if split else P()
+        return jax.shard_map(fn, mesh=mesh, in_specs=(spec,) * len(args),
+                             out_specs=spec, check_vma=False)(*args)
+
+    return per_shard
+
+
 def install(mesh: Mesh, dp_axes=("data",), model_axes=("model",),
             vocab_on_model: bool = True, seq_shard_attn: bool = False) -> None:
-    global _ACT, _LOGITS, _ATTN_Q, _PIN
+    global _ACT, _LOGITS, _ATTN_Q, _PIN, _MESH
     dp = tuple(a for a in dp_axes if a in mesh.shape) or None
     mdl = tuple(a for a in model_axes if a in mesh.shape) or None
 
@@ -87,6 +108,7 @@ def install(mesh: Mesh, dp_axes=("data",), model_axes=("model",),
     _ACT, _LOGITS = _act, _logits
     _ATTN_Q = _attn_q if seq_shard_attn else None
     _PIN = _pin
+    _MESH = (mesh, dp)
 
 
 def _size(mesh: Mesh, axes) -> int:
@@ -97,5 +119,5 @@ def _size(mesh: Mesh, axes) -> int:
 
 
 def clear() -> None:
-    global _ACT, _LOGITS, _ATTN_Q, _PIN
-    _ACT = _LOGITS = _ATTN_Q = _PIN = None
+    global _ACT, _LOGITS, _ATTN_Q, _PIN, _MESH
+    _ACT = _LOGITS = _ATTN_Q = _PIN = _MESH = None

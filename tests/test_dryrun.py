@@ -28,7 +28,8 @@ def test_small_mesh_cell_lowers_and_compiles():
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
         import json, jax
         from repro.launch.cells import build_cell, analyze_compiled
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         out = {}
         for arch, shape in [("qwen2-1.5b", "train_4k"), ("glm4-9b", "decode_32k")]:
             cell = build_cell(arch, shape, mesh)
@@ -147,9 +148,10 @@ def test_elastic_rescale_across_mesh_sizes(tmp_path):
         from repro.models import build_model
         from repro.parallel import Plan
         from repro.parallel.sharding import make_param_shardings
+        from repro.launch.mesh import make_mesh
         from repro.train import OptimizerConfig, init_train_state
 
-        mesh = jax.make_mesh((2, 2), ("data", "model"))
+        mesh = make_mesh((2, 2), ("data", "model"))
         cfg = reduced(get_config("qwen2-1.5b"))
         model = build_model(cfg)
         plan = Plan()
@@ -173,9 +175,10 @@ def test_elastic_rescale_across_mesh_sizes(tmp_path):
         from repro.ft.elastic import elastic_restart
         from repro.models import build_model
         from repro.parallel import Plan
+        from repro.launch.mesh import make_mesh
         from repro.train import OptimizerConfig, init_train_state
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        mesh = make_mesh((4, 2), ("data", "model"))
         cfg = reduced(get_config("qwen2-1.5b"))
         model = build_model(cfg)
         plan = Plan()

@@ -64,6 +64,66 @@ def test_flash_attention_q_offset(rng):
     np.testing.assert_allclose(np.asarray(out), np.asarray(want), atol=2e-5)
 
 
+@pytest.mark.parametrize("causal,window,q_offset", [
+    (True, 0, 0), (False, 0, 0), (True, 32, 0), (True, 0, 16)])
+def test_flash_attention_grads_match_oracle(rng, causal, window, q_offset):
+    """Pallas forward + XLA flash backward (the custom VJP of the
+    tpu/interpret backends) against autodiff of the oracle."""
+    B, S, H, KH, D = 2, 64, 4, 2, 32
+    q = _rand(rng, (B, S, H, D), jnp.float32)
+    k = _rand(rng, (B, S + q_offset, KH, D), jnp.float32)
+    v = _rand(rng, (B, S + q_offset, KH, D), jnp.float32)
+    w = _rand(rng, (B, S, H, D), jnp.float32)  # a non-uniform cotangent
+
+    def f(q, k, v):
+        out = ops.flash_attention(q, k, v, causal=causal, window=window,
+                                  q_offset=q_offset, block_q=16, block_k=16)
+        return (out * w).sum()
+
+    def g(q, k, v):
+        out = ref.attention(q, k, v, causal=causal, window=window,
+                            q_offset=q_offset)
+        return (out * w).sum()
+
+    np.testing.assert_allclose(f(q, k, v), g(q, k, v), rtol=1e-5)
+    gf = jax.grad(f, argnums=(0, 1, 2))(q, k, v)
+    gr = jax.grad(g, argnums=(0, 1, 2))(q, k, v)
+    for a, b in zip(gf, gr):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b), atol=2e-4)
+
+
+@pytest.mark.parametrize("op", ["mlstm_scan", "ssm_scan", "moe_gmm"])
+def test_forward_only_kernels_refuse_grad(rng, op):
+    """Kernels without a backward raise under jax.grad instead of
+    differentiating an oracle in their place."""
+    x = _rand(rng, (1, 2, 8, 8), jnp.float32)
+    calls = {
+        "mlstm_scan": lambda x: ops.mlstm_scan(x, x, x, x[..., 0], x[..., 0]),
+        "ssm_scan": lambda x: ops.ssm_scan(
+            x[0], x[0], -jnp.ones((8, 4)), x[0, :, :, :4], x[0, :, :, :4],
+            jnp.ones((8,))),
+        "moe_gmm": lambda x: ops.moe_gmm(
+            x[0, 0], jnp.array([8], jnp.int32), x[0, :1], block_m=8),
+    }
+    fn = calls[op]
+    assert np.isfinite(np.asarray(fn(x))).all()  # the forward runs
+    with pytest.raises(NotImplementedError, match=f"{op}.*no backward"):
+        jax.grad(lambda x: fn(x).sum())(x)
+
+
+def test_backend_resolves_from_device():
+    """Unset, the backend follows the device: ref on the CPU."""
+    saved = ops._BACKEND
+    try:
+        ops._BACKEND = None
+        assert ops.get_backend() == ("tpu" if jax.default_backend() == "tpu"
+                                     else "ref")
+    finally:
+        ops._BACKEND = saved
+    with pytest.raises(ValueError):
+        ops.set_backend("cuda")
+
+
 # ===========================================================================
 # XLA flash attention (custom VJP) — fwd and grads vs oracle
 # ===========================================================================
@@ -185,6 +245,20 @@ def test_moe_gmm_matches_oracle(rng, M, D, F, E, bm, dtype):
         np.asarray(out, np.float32), np.asarray(want, np.float32),
         atol=5 * TOL[dtype], rtol=5 * TOL[dtype],
     )
+
+
+def test_moe_gmm_tiled_matches_oracle(rng):
+    """Wide enough that F (640 -> five 128-wide blocks) and D (1024 ->
+    two 512-wide blocks) are tiled: accumulation over the expert and
+    contraction grid axes."""
+    M, D, F, E = 64, 1024, 640, 4
+    toks = _rand(rng, (M, D), jnp.float32)
+    sizes = jnp.asarray(rng.multinomial(M, np.ones(E) / E).astype(np.int32))
+    w = _rand(rng, (E, D, F), jnp.float32)
+    out = ops.moe_gmm(toks, sizes, w, block_m=16)
+    want = ref.moe_gmm(toks, sizes, w)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               atol=1e-3, rtol=1e-4)
 
 
 def test_moe_gmm_empty_groups(rng):
