@@ -53,6 +53,7 @@ def init_mlp(pb: ParamBuilder, cfg: ModelConfig, L: int):
     pb.p("mlp_wd", (L, F, D), ("layers", "mlp", "embed"))
 
 
+@jax.named_scope("lm.mlp")
 def apply_mlp(p: Dict[str, Any], x: jax.Array, cfg: ModelConfig) -> jax.Array:
     dt = x.dtype
     hu = jnp.einsum("bsd,df->bsf", x, p["mlp_wu"].astype(dt))
@@ -118,6 +119,10 @@ def init_lm(cfg: ModelConfig, rng: jax.Array) -> Tuple[Pytree, Pytree]:
 # ===========================================================================
 # Shared pieces
 # ===========================================================================
+# Device scopes of the dense decoder (``lm.embed``, ``lm.attn``,
+# ``lm.mlp``, ``lm.head``) name its parts in a profile's op metadata;
+# they cost nothing at run time.
+@jax.named_scope("lm.embed")
 def embed_tokens(params: Pytree, cfg: ModelConfig, tokens: jax.Array,
                  extra: Optional[Dict[str, jax.Array]] = None) -> jax.Array:
     dt = jnp.dtype(cfg.dtype)
@@ -130,6 +135,7 @@ def embed_tokens(params: Pytree, cfg: ModelConfig, tokens: jax.Array,
     return x
 
 
+@jax.named_scope("lm.head")
 def lm_logits(params: Pytree, cfg: ModelConfig, x: jax.Array) -> jax.Array:
     xn = apply_norm(params, "final", x, cfg.norm)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
@@ -169,7 +175,8 @@ def _block_train(cfg: ModelConfig, p: Dict[str, Any], x: jax.Array,
         )
         x = x + mix
     else:
-        x = x + attend_train(p, h, cfg, causal=True)
+        with jax.named_scope("lm.attn"):
+            x = x + attend_train(p, h, cfg, causal=True)
     h2 = apply_norm(p, "norm2", x, cfg.norm)
     if cfg.num_experts > 0:
         out, aux = apply_moe(p, h2, cfg)
@@ -398,11 +405,12 @@ def prefill(params: Pytree, cfg: ModelConfig, tokens: jax.Array,
         pl_, fl = xs
         xx = hints.act(xx)
         h = apply_norm(pl_, "norm1", xx, cfg.norm)
-        q, k, v = qkv(pl_, h, cfg)
-        q = apply_rope(q, cos, sin)
-        k = apply_rope(k, cos, sin)
-        attn = ops.flash_attention(q, k, v, causal=True)
-        xx = xx + out_proj(pl_, attn)
+        with jax.named_scope("lm.attn"):
+            q, k, v = qkv(pl_, h, cfg)
+            q = apply_rope(q, cos, sin)
+            k = apply_rope(k, cos, sin)
+            attn = ops.flash_attention(q, k, v, causal=True)
+            xx = xx + out_proj(pl_, attn)
         h2 = apply_norm(pl_, "norm2", xx, cfg.norm)
         if cfg.num_experts > 0:
             out, _ = apply_moe(pl_, h2, cfg)
@@ -567,9 +575,10 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
             pl_, kp, vp = xs
             xx = hints.act(xx)
             h = apply_norm(pl_, "norm1", xx, cfg.norm)
-            attn_out, nkp, nvp = attend_decode_paged(
-                pl_, h, kp, vp, page_table, pos, cfg
-            )
+            with jax.named_scope("lm.attn"):
+                attn_out, nkp, nvp = attend_decode_paged(
+                    pl_, h, kp, vp, page_table, pos, cfg
+                )
             xx = xx + attn_out
             h2 = apply_norm(pl_, "norm2", xx, cfg.norm)
             if cfg.num_experts > 0:
@@ -603,7 +612,8 @@ def decode_step(params: Pytree, cfg: ModelConfig, cache: Pytree,
             pl_, fl, kc, vc = xs
             xx = hints.act(xx)
             h = apply_norm(pl_, "norm1", xx, cfg.norm)
-            attn_out, nk, nv, _ = attend_decode(pl_, h, kc, vc, pos, cfg)
+            with jax.named_scope("lm.attn"):
+                attn_out, nk, nv, _ = attend_decode(pl_, h, kc, vc, pos, cfg)
             xx = xx + attn_out
             h2 = apply_norm(pl_, "norm2", xx, cfg.norm)
             if cfg.num_experts > 0:

@@ -85,11 +85,21 @@ rows per slot at admission so a verify pass never writes past the
 reservation.  Greedy output is bit-identical to non-speculative decode
 and temperature output is exactly target-distributed (rejection
 sampling) — see ``docs/serving.md`` for the proposer matrix.
+
+Tracing: the fused and paged paths mark their phases with
+``jax.profiler.TraceAnnotation`` host spans (``serve.step`` holding
+``serve.admit`` > ``serve.prefill``, ``serve.upload``, ``serve.decode``,
+``serve.readback``, ``serve.retire``), so a profile puts every gap of the
+device under the engine phase that left it waiting.  Span arguments are
+host integers and short strings, built only while a trace is being
+recorded; a span adds no device work and no host-device synchronisation.
+``docs/serving.md`` lists the spans and their arguments.
 """
 from __future__ import annotations
 
 import dataclasses
 import hashlib
+import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Tuple
 
@@ -104,6 +114,8 @@ Pytree = Any
 
 _MIN_SEQ_BUCKET = 8
 
+_span = jax.profiler.TraceAnnotation
+
 
 @dataclasses.dataclass
 class Request:
@@ -112,6 +124,10 @@ class Request:
     max_new_tokens: int = 32
     temperature: float = 0.0
     extra: Optional[Dict[str, np.ndarray]] = None
+    # host clock (``time.perf_counter``) at ``submit``: the queue wait
+    # that the ``serve.admit`` span reports
+    submitted: float = dataclasses.field(default=0.0, repr=False,
+                                         compare=False)
 
 
 @dataclasses.dataclass
@@ -260,8 +276,8 @@ def _make_prefill_insert(model: Model, max_seq: int, axes: Pytree,
     engine's slots — one dispatch per admission group."""
     from repro.models import sampling
 
-    def fn(params, batched_cache, tokens, extra, lens, slots, n_valid,
-           base_key, temps):
+    def prefill_insert(params, batched_cache, tokens, extra, lens, slots,
+                       n_valid, base_key, temps):
         logits, cache1 = model.prefill(
             params, tokens, extra, max_seq=max_seq,
             lens=lens if use_lens else None,
@@ -271,7 +287,7 @@ def _make_prefill_insert(model: Model, max_seq: int, axes: Pytree,
         new_cache = _insert_rows(batched_cache, cache1, slots, n_valid, axes)
         return toks, new_cache
 
-    return fn
+    return prefill_insert
 
 
 def _make_paged_prefill_insert(model: Model, page: int, use_lens: bool):
@@ -288,8 +304,9 @@ def _make_paged_prefill_insert(model: Model, page: int, use_lens: bool):
     every admission batch of the same bucket shape."""
     from repro.models import sampling
 
-    def fn(params, k_pool, v_pool, pos, tokens, extra, lens, slots, n_valid,
-           src_row, src_page, dst_page, n_copy, base_key, temps):
+    def paged_prefill_insert(params, k_pool, v_pool, pos, tokens, extra,
+                             lens, slots, n_valid, src_row, src_page,
+                             dst_page, n_copy, base_key, temps):
         # the mini-cache is padded to a page multiple so every prompt
         # page slices in bounds (pad K/V is garbage but masked by kv_len
         # until decode overwrites it, exactly like the dense engine)
@@ -324,7 +341,7 @@ def _make_paged_prefill_insert(model: Model, page: int, use_lens: bool):
             0, n_valid, lambda i, p: p.at[slots[i]].set(lens[i]), pos)
         return toks, k_pool, v_pool, pos
 
-    return fn
+    return paged_prefill_insert
 
 
 def _make_decode_chunk(model: Model, steps: int):
@@ -333,8 +350,8 @@ def _make_decode_chunk(model: Model, steps: int):
     their later tokens are dead.  Emits ``(steps, B)`` tokens — the
     chunk's single host transfer."""
 
-    def fn(params, cache, last_token, base_key, temps, active, counts,
-           budgets, eos_id, greedy_only=False):
+    def decode_chunk(params, cache, last_token, base_key, temps, active,
+                     counts, budgets, eos_id, greedy_only=False):
         def body(carry, _):
             cache, last, act, cnt = carry
             toks, cache = model.decode_and_sample(
@@ -352,7 +369,7 @@ def _make_decode_chunk(model: Model, steps: int):
         )
         return seq, cache
 
-    return fn
+    return decode_chunk
 
 
 def _make_spec_chunk(model: Model, spec_k: int, rounds: int, ngram_n: int,
@@ -376,9 +393,9 @@ def _make_spec_chunk(model: Model, spec_k: int, rounds: int, ngram_n: int,
     survived) columns — the chunk's single host transfer."""
     K = spec_k
 
-    def fn(params, cache, draft_params, draft_cache, last_token, hist,
-           base_key, temps, active, counts, budgets, eos_id,
-           greedy_only=False):
+    def spec_chunk(params, cache, draft_params, draft_cache, last_token, hist,
+                   base_key, temps, active, counts, budgets, eos_id,
+                   greedy_only=False):
         B = last_token.shape[0]
         slots = jnp.arange(B)
 
@@ -450,7 +467,7 @@ def _make_spec_chunk(model: Model, spec_k: int, rounds: int, ngram_n: int,
             None, length=rounds)
         return rows, cache, dcache, hist
 
-    return fn
+    return spec_chunk
 
 
 class ServeEngine:
@@ -667,6 +684,7 @@ class ServeEngine:
                 + f" exceeds max_seq={self.max_seq}: the decode would "
                 f"overflow the KV cache"
             )
+        req.submitted = time.perf_counter()
         self.queue.append(req)
 
     def _to_host(self, arr: jax.Array) -> np.ndarray:
@@ -727,17 +745,29 @@ class ServeEngine:
         return picked
 
     def _admit(self) -> None:
+        """Admit queued requests: on the fused and paged paths inside a
+        ``serve.admit`` span whose arguments describe what was admitted."""
         if self.engine == "legacy":
             self._admit_legacy()
             return
-        if self.engine == "paged":
-            self._admit_paged()
-            return
+        with _span("serve.admit") as sp:
+            t = time.perf_counter()
+            admitted = (self._admit_paged() if self.engine == "paged"
+                        else self._admit_fused())
+            if sp.is_enabled():
+                sp.set_metadata(
+                    admitted=len(admitted),
+                    prompt_tokens=sum(len(r.prompt) for r in admitted),
+                    uids=" ".join(str(r.uid) for r in admitted),
+                    queue_wait_ms=max((1e3 * (t - r.submitted)
+                                       for r in admitted), default=0.0))
+
+    def _admit_fused(self) -> List[Request]:
         if not self.queue:
-            return
+            return []
         free = np.flatnonzero(~self.active)
         if free.size == 0:
-            return
+            return []
         selected = self._select(int(free.size))
         pairs = [(int(free[i]), req) for i, req in enumerate(selected)]
         groups: Dict[Tuple, List[Tuple[int, Request]]] = {}
@@ -745,6 +775,7 @@ class ServeEngine:
             groups.setdefault(self._group_key(req), []).append((slot, req))
         for (kind, seq_len, _), members in groups.items():
             self._admit_group(kind, seq_len, members)
+        return selected
 
     def _admit_group(self, kind: str, seq_len: int,
                      members: List[Tuple[int, Request]]) -> None:
@@ -769,13 +800,17 @@ class ServeEngine:
                 extra[k] = jnp.asarray(np.stack(rows))
         fn = (self._prefill_insert_pad if kind == "pad"
               else self._prefill_insert_exact)
-        first, self.cache = fn(
-            self.params, self.cache, jnp.asarray(tokens), extra,
-            jnp.asarray(lens), jnp.asarray(slots), jnp.int32(n),
-            self.base_key, jnp.asarray(temps),
-        )
-        self._admit_draft(kind, tokens, lens, slots, temps, n)
-        first = np.asarray(first)
+        with _span("serve.prefill") as sp:
+            if sp.is_enabled():
+                sp.set_metadata(rows=n, bucket=seq_len,
+                                prompt_tokens=int(lens[:n].sum()))
+            first, self.cache = fn(
+                self.params, self.cache, jnp.asarray(tokens), extra,
+                jnp.asarray(lens), jnp.asarray(slots), jnp.int32(n),
+                self.base_key, jnp.asarray(temps),
+            )
+            self._admit_draft(kind, tokens, lens, slots, temps, n)
+            first = np.asarray(first)
         for i, (slot, req) in enumerate(members):
             self._place(slot, req, int(first[i]))
 
@@ -831,12 +866,12 @@ class ServeEngine:
             pages.append(pid)
         return pages, copies
 
-    def _admit_paged(self) -> None:
+    def _admit_paged(self) -> List[Request]:
         if not self.queue:
-            return
+            return []
         free = np.flatnonzero(~self.active)
         if free.size == 0:
-            return
+            return []
         selected = self._select(int(free.size))
         admitted: List[Tuple[int, Request, List[int], List[int]]] = []
         for i, req in enumerate(selected):
@@ -849,12 +884,13 @@ class ServeEngine:
                 break
             admitted.append((int(free[len(admitted)]), req, *plan))
         if not admitted:
-            return
+            return []
         groups: Dict[Tuple, List[Tuple[int, Request, List[int], List[int]]]] = {}
         for entry in admitted:
             groups.setdefault(self._group_key(entry[1]), []).append(entry)
         for (kind, seq_len, _), members in groups.items():
             self._admit_group_paged(kind, seq_len, members)
+        return [entry[1] for entry in admitted]
 
     def _admit_group_paged(self, kind: str, seq_len: int, members) -> None:
         n = len(members)
@@ -898,17 +934,22 @@ class ServeEngine:
                 extra[k] = jnp.asarray(np.stack(rows))
         fn = (self._paged_insert_pad if kind == "pad"
               else self._paged_insert_exact)
-        toks, nk, nv, npos = fn(
-            self.params, self.cache["k_pool"], self.cache["v_pool"],
-            self.cache["pos"], jnp.asarray(tokens), extra,
-            jnp.asarray(lens), jnp.asarray(slots), jnp.int32(n),
-            jnp.asarray(sr), jnp.asarray(sp), jnp.asarray(dp),
-            jnp.int32(n_copy), self.base_key, jnp.asarray(temps),
-        )
-        self.cache = {"k_pool": nk, "v_pool": nv,
-                      "page_table": self.cache["page_table"], "pos": npos}
-        self._admit_draft(kind, tokens, lens, slots, temps, n)
-        first = np.asarray(toks)
+        with _span("serve.prefill") as span:
+            if span.is_enabled():
+                span.set_metadata(rows=n, bucket=seq_len,
+                                  prompt_tokens=int(lens[:n].sum()))
+            toks, nk, nv, npos = fn(
+                self.params, self.cache["k_pool"], self.cache["v_pool"],
+                self.cache["pos"], jnp.asarray(tokens), extra,
+                jnp.asarray(lens), jnp.asarray(slots), jnp.int32(n),
+                jnp.asarray(sr), jnp.asarray(sp), jnp.asarray(dp),
+                jnp.int32(n_copy), self.base_key, jnp.asarray(temps),
+            )
+            self.cache = {"k_pool": nk, "v_pool": nv,
+                          "page_table": self.cache["page_table"],
+                          "pos": npos}
+            self._admit_draft(kind, tokens, lens, slots, temps, n)
+            first = np.asarray(toks)
         for i, (slot, req, _, _) in enumerate(members):
             self._place(slot, req, int(first[i]))
 
@@ -1016,35 +1057,71 @@ class ServeEngine:
                 elif len(self.emitted[slot]) >= req.max_new_tokens:
                     self._retire(slot, "length")
 
+    def _decode_args(self, steps: int) -> Dict[str, int]:
+        """``serve.decode`` arguments: active rows, rows computed, live
+        K/V tokens of the active rows, and the K/V positions the step
+        spans over every computed row (page-table width x page, or
+        ``max_seq``)."""
+        width = (self._max_pages * self.page_size if self.engine == "paged"
+                 else self.max_seq)
+        return {"rows": int(self.active.sum()), "batch": self.max_batch,
+                "kv_tokens": self.live_tokens,
+                "kv_capacity": self.max_batch * width, "steps": steps}
+
+    def _collect(self, out: jax.Array, consume) -> None:
+        """Read a decode dispatch's tokens back (``serve.readback``) and
+        apply them with ``consume`` (``serve.retire``)."""
+        with _span("serve.readback"):
+            rows = self._to_host(out)
+        with _span("serve.retire") as sp:
+            n0 = len(self.done)
+            consume(rows)
+            if sp.is_enabled():
+                done = self.done[n0:]
+                sp.set_metadata(finished=len(done),
+                                uids=" ".join(str(c.uid) for c in done))
+
     def step(self) -> None:
         """One engine iteration: admit new work, decode one token for every
         active slot, retire finished slots.  On the fused path this is one
         device dispatch and one (B,) host transfer."""
-        self._admit()
-        self._sync_ptable()
+        if self.engine == "legacy":
+            self._step_legacy()
+            return
+        with _span("serve.step", queued=len(self.queue)):
+            self._admit()
+            with _span("serve.upload"):
+                self._sync_ptable()
+            if not self.active.any():
+                return
+            with _span("serve.decode") as sp:
+                if sp.is_enabled():
+                    sp.set_metadata(**self._decode_args(1))
+                toks, self.cache = self._decode_sample(
+                    self.params, self.cache,
+                    jnp.asarray(self.last_token)[:, None],
+                    self.base_key, jnp.asarray(self.temps),
+                    greedy_only=self._all_greedy(),
+                )
+            self._collect(toks, lambda row: self._consume(row[None]))
+
+    def _step_legacy(self) -> None:
+        self._admit_legacy()
         if not self.active.any():
             return
-        if self.engine == "legacy":
-            logits, self.cache = self._decode(
-                self.params, self.cache, jnp.asarray(self.last_token)[:, None]
-            )
-            # full (B, V) host copy — the cost the fused path removes;
-            # routed through _to_host so the instrumentation tells the truth
-            logits = self._to_host(logits).astype(np.float32)
-            row = np.zeros(self.max_batch, np.int32)
-            for slot in range(self.max_batch):  # one dispatch per slot
-                if not self.active[slot]:
-                    continue
-                row[slot] = self._sample(jnp.asarray(logits[slot]),
-                                         self.req[slot].temperature)
-            self._consume(row[None])
-            return
-        toks, self.cache = self._decode_sample(
-            self.params, self.cache, jnp.asarray(self.last_token)[:, None],
-            self.base_key, jnp.asarray(self.temps),
-            greedy_only=self._all_greedy(),
+        logits, self.cache = self._decode(
+            self.params, self.cache, jnp.asarray(self.last_token)[:, None]
         )
-        self._consume(self._to_host(toks)[None])
+        # full (B, V) host copy — the cost the fused path removes;
+        # routed through _to_host so the instrumentation tells the truth
+        logits = self._to_host(logits).astype(np.float32)
+        row = np.zeros(self.max_batch, np.int32)
+        for slot in range(self.max_batch):  # one dispatch per slot
+            if not self.active[slot]:
+                continue
+            row[slot] = self._sample(jnp.asarray(logits[slot]),
+                                     self.req[slot].temperature)
+        self._consume(row[None])
 
     def step_chunk(self) -> int:
         """One chunked iteration: admit, then decode ``decode_chunk``
@@ -1053,23 +1130,29 @@ class ServeEngine:
         if self._decode_chunk is None:
             self.step()
             return 1
-        self._admit()
-        self._sync_ptable()
-        if not self.active.any():
-            return 0
-        budgets = np.asarray(
-            [r.max_new_tokens if r is not None else 0 for r in self.req],
-            np.int32,
-        )
-        counts = np.asarray([len(e) for e in self.emitted], np.int32)
-        seq, self.cache = self._decode_chunk(
-            self.params, self.cache, jnp.asarray(self.last_token),
-            self.base_key, jnp.asarray(self.temps), jnp.asarray(self.active),
-            jnp.asarray(counts), jnp.asarray(budgets), jnp.int32(self.eos_id),
-            greedy_only=self._all_greedy(),
-        )
-        self._consume(self._to_host(seq))
-        return self.decode_chunk
+        with _span("serve.step", queued=len(self.queue)):
+            self._admit()
+            with _span("serve.upload"):
+                self._sync_ptable()
+            if not self.active.any():
+                return 0
+            budgets = np.asarray(
+                [r.max_new_tokens if r is not None else 0 for r in self.req],
+                np.int32,
+            )
+            counts = np.asarray([len(e) for e in self.emitted], np.int32)
+            with _span("serve.decode") as sp:
+                if sp.is_enabled():
+                    sp.set_metadata(**self._decode_args(self.decode_chunk))
+                seq, self.cache = self._decode_chunk(
+                    self.params, self.cache, jnp.asarray(self.last_token),
+                    self.base_key, jnp.asarray(self.temps),
+                    jnp.asarray(self.active), jnp.asarray(counts),
+                    jnp.asarray(budgets), jnp.int32(self.eos_id),
+                    greedy_only=self._all_greedy(),
+                )
+            self._collect(seq, self._consume)
+            return self.decode_chunk
 
     # ---- speculative decode ------------------------------------------
     def _consume_spec(self, rows: np.ndarray) -> None:
@@ -1109,27 +1192,34 @@ class ServeEngine:
         draft/verify rounds in a single scanned dispatch — up to
         ``decode_chunk * (spec_k + 1)`` tokens per slot from one host
         transfer.  Returns the rounds executed (0 when idle)."""
-        self._admit()
-        self._sync_ptable()
-        self._sync_hist()
-        if not self.active.any():
-            return 0
-        budgets = np.asarray(
-            [r.max_new_tokens if r is not None else 0 for r in self.req],
-            np.int32,
-        )
-        counts = np.asarray([len(e) for e in self.emitted], np.int32)
-        rows, self.cache, dcache, self.hist = self._spec_chunk(
-            self.params, self.cache, self.draft_params, self._draft_cache,
-            jnp.asarray(self.last_token), self.hist, self.base_key,
-            jnp.asarray(self.temps), jnp.asarray(self.active),
-            jnp.asarray(counts), jnp.asarray(budgets),
-            jnp.int32(self.eos_id), greedy_only=self._all_greedy(),
-        )
-        if self.draft is not None:
-            self._draft_cache = dcache
-        self._consume_spec(self._to_host(rows))
-        return max(1, self.decode_chunk)
+        with _span("serve.step", queued=len(self.queue)):
+            self._admit()
+            with _span("serve.upload"):
+                self._sync_ptable()
+                self._sync_hist()
+            if not self.active.any():
+                return 0
+            budgets = np.asarray(
+                [r.max_new_tokens if r is not None else 0 for r in self.req],
+                np.int32,
+            )
+            counts = np.asarray([len(e) for e in self.emitted], np.int32)
+            rounds = max(1, self.decode_chunk)
+            with _span("serve.decode") as sp:
+                if sp.is_enabled():
+                    sp.set_metadata(**self._decode_args(rounds))
+                rows, self.cache, dcache, self.hist = self._spec_chunk(
+                    self.params, self.cache, self.draft_params,
+                    self._draft_cache, jnp.asarray(self.last_token),
+                    self.hist, self.base_key, jnp.asarray(self.temps),
+                    jnp.asarray(self.active), jnp.asarray(counts),
+                    jnp.asarray(budgets), jnp.int32(self.eos_id),
+                    greedy_only=self._all_greedy(),
+                )
+            if self.draft is not None:
+                self._draft_cache = dcache
+            self._collect(rows, self._consume_spec)
+            return rounds
 
     def run(self, max_steps: int = 10_000) -> List[Completion]:
         steps = 0

@@ -75,8 +75,11 @@ def make_train_step(model: Model, opt_cfg: OptimizerConfig, plan: Plan,
         moe_mod.set_moe_sharding_hint(None)
         moe_mod.set_moe_impl("scatter")
 
+    # named scopes name the step's parts in a profile: the forward is
+    # ``jvp(train.loss)``, the backward ``transpose(jvp(train.loss))``
     def loss_of(params, batch):
-        return model.loss(params, batch, remat=plan.remat)
+        with jax.named_scope("train.loss"):
+            return model.loss(params, batch, remat=plan.remat)
 
     grad_fn = jax.value_and_grad(loss_of, has_aux=True)
 
@@ -120,7 +123,9 @@ def make_train_step(model: Model, opt_cfg: OptimizerConfig, plan: Plan,
             new_err = jax.tree.map(lambda t: t[1], pairs,
                                    is_leaf=lambda t: isinstance(t, tuple))
 
-        new_params, new_opt, opt_metrics = adamw_update(grads, state["opt"], params, opt_cfg)
+        with jax.named_scope("train.optimizer"):
+            new_params, new_opt, opt_metrics = adamw_update(
+                grads, state["opt"], params, opt_cfg)
         new_state = {
             "params": new_params,
             "opt": new_opt,
