@@ -107,6 +107,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.kernels.paged_attention import pages_per_block
 from repro.models import speculate
 from repro.models.api import Model
 
@@ -1061,12 +1062,24 @@ class ServeEngine:
         """``serve.decode`` arguments: active rows, rows computed, live
         K/V tokens of the active rows, and the K/V positions the step
         spans over every computed row (page-table width x page, or
-        ``max_seq``)."""
+        ``max_seq``).  A paged engine adds ``kv_pages_walked``: the
+        table pages in the blocks the paged kernel walks for the active
+        rows, each row's ``ceil(kv_len / page)`` rounded up to its block
+        of pages (``kv_len`` counts the ``spec_k`` draft rows a verify
+        reads)."""
         width = (self._max_pages * self.page_size if self.engine == "paged"
                  else self.max_seq)
-        return {"rows": int(self.active.sum()), "batch": self.max_batch,
+        args = {"rows": int(self.active.sum()), "batch": self.max_batch,
                 "kv_tokens": self.live_tokens,
                 "kv_capacity": self.max_batch * width, "steps": steps}
+        if self.engine == "paged":
+            ppb = pages_per_block(self.page_size, self._max_pages)
+            bk = ppb * self.page_size
+            args["kv_pages_walked"] = sum(
+                ppb * -(-min(len(self.req[s].prompt) + len(self.emitted[s])
+                             + self.spec_k, width) // bk)
+                for s in range(self.max_batch) if self.active[s])
+        return args
 
     def _collect(self, out: jax.Array, consume) -> None:
         """Read a decode dispatch's tokens back (``serve.readback``) and

@@ -10,6 +10,7 @@ import pytest
 from repro.configs import get_config, reduced
 from repro.kernels import ops, ref
 from repro.kernels.flash_xla import paged_attention_xla
+from repro.kernels.paged_attention import pages_per_block
 from repro.models import build_model
 from repro.serve.engine import PagePool, Request, ServeEngine
 
@@ -41,19 +42,39 @@ def _paged_setup(rng, B, KH, G, D, page, max_pages, num_pages, kv_len,
 # ===========================================================================
 # kernel: Pallas (interpret) and XLA fallback vs gather oracle
 # ===========================================================================
+# Lengths at the walk's edges for a 20-page table of 16-token pages,
+# walked in blocks of 16 pages (256 tokens): an empty row, one token,
+# exactly one block, one block plus one, and the full table (the second
+# block, 4 pages of 16, is cut short by the table's width).
+_EDGE_LENS = (0, 1, 256, 257, 320)
+
+
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 @pytest.mark.parametrize(
-    "B,KH,G,D,page,max_pages",
+    "B,KH,G,D,page,max_pages,kv_len",
     [
-        (1, 4, 1, 32, 16, 4),   # MHA
-        (4, 2, 4, 32, 16, 4),   # GQA
-        (2, 1, 8, 16, 8, 8),    # MQA, small pages
-        (2, 2, 2, 48, 16, 4),   # head_dim padded to the 128 lane
+        pytest.param(1, 4, 1, 32, 16, 4, None, id="1-4-1-32-16-4"),   # MHA
+        pytest.param(4, 2, 4, 32, 16, 4, None, id="4-2-4-32-16-4"),   # GQA
+        # MQA, small pages
+        pytest.param(2, 1, 8, 16, 8, 8, None, id="2-1-8-16-8-8"),
+        # head_dim padded to the 128 lane
+        pytest.param(2, 2, 2, 48, 16, 4, None, id="2-2-2-48-16-4"),
+        # qwen2-1.5b's group (12 heads over 2 KV heads), walk edges
+        pytest.param(5, 2, 6, 128, 16, 20, _EDGE_LENS, id="qwen2-G6-edges"),
+        # glm4-9b's group (32 heads over 2 KV heads), walk edges
+        pytest.param(5, 2, 16, 128, 16, 20, _EDGE_LENS, id="glm4-G16-edges"),
+        # 8-token pages: blocks of 32 pages over a 40-page table
+        pytest.param(5, 1, 6, 64, 8, 40, _EDGE_LENS, id="page8-G6-edges"),
+        # several blocks, random lengths
+        pytest.param(3, 2, 6, 128, 16, 52, None, id="qwen2-G6-52pages"),
     ],
 )
-def test_paged_kernel_matches_oracle(rng, B, KH, G, D, page, max_pages, dtype):
+def test_paged_kernel_matches_oracle(rng, B, KH, G, D, page, max_pages,
+                                     kv_len, dtype):
     num_pages = 1 + B * max_pages
-    kv_len = rng.integers(1, page * max_pages + 1, B)
+    if kv_len is None:
+        kv_len = rng.integers(1, page * max_pages + 1, B)
+    kv_len = np.asarray(kv_len)
     q, kp, vp, table, lens = _paged_setup(
         rng, B, KH, G, D, page, max_pages, num_pages, kv_len, dtype)
     want = ref.paged_attention(q, kp, vp, table, lens)
@@ -62,9 +83,12 @@ def test_paged_kernel_matches_oracle(rng, B, KH, G, D, page, max_pages, dtype):
         got = ops.paged_decode_attention(q, kp, vp, table, kv_len=lens)
     finally:
         ops.set_backend("ref")
+    live = kv_len > 0
     np.testing.assert_allclose(
-        np.asarray(got, np.float32), np.asarray(want, np.float32),
+        np.asarray(got, np.float32)[live], np.asarray(want, np.float32)[live],
         atol=TOL[dtype], rtol=TOL[dtype])
+    # an empty row walks nothing and writes zeros
+    np.testing.assert_array_equal(np.asarray(got, np.float32)[~live], 0.0)
 
 
 @pytest.mark.parametrize("kv_len", [1, 7, 16, 64])
@@ -97,9 +121,41 @@ def test_paged_oracle_equals_dense_gather(rng):
     np.testing.assert_array_equal(np.asarray(dense), np.asarray(paged))
 
 
+def _scribble_dead(table, lens, pool, value):
+    """``pool`` with ``value`` written over every position a row may not
+    read: unmapped pages (the null page 0 among them) and, in mapped
+    pages, every position at or past the row's length."""
+    table, lens = np.asarray(table), np.asarray(lens)
+    page = pool.shape[2]
+    dead = np.ones(pool.shape[1:3], bool)  # (P, page)
+    for b in range(table.shape[0]):
+        for j, pid in enumerate(table[b]):
+            if pid >= 0:
+                dead[pid] = np.arange(j * page, (j + 1) * page) >= lens[b]
+    return jnp.where(jnp.asarray(dead)[None, :, :, None],
+                     jnp.asarray(value, pool.dtype), pool)
+
+
+def _with_room(rng, B, KH, G, D, page, max_pages, kv_len, room, T=1):
+    """``_paged_setup`` with ``room`` more tokens of pages mapped past
+    each row's length (the engine's decode reservation), and a last
+    row parked: unmapped, its stale length still set."""
+    mapped = np.minimum(np.asarray(kv_len) + room, page * max_pages)
+    q, kp, vp, table, _ = _paged_setup(
+        rng, B, KH, G, D, page, max_pages, 1 + B * max_pages, mapped)
+    if T > 1:
+        q = _rand(rng, (B, T, KH * G, D), jnp.float32)
+    table = table.at[-1].set(-1)
+    return q, kp, vp, table, jnp.asarray(kv_len, jnp.int32)
+
+
 def test_paged_kernel_ignores_dead_pages(rng):
     """Entries past kv_len (including -1/unmapped) must not contribute:
-    scribbling over every unmapped page leaves the output unchanged."""
+    scribbling over every unmapped page leaves the output unchanged.
+    The Pallas kernel neither reads nor computes with them: NaN written
+    over unmapped pages, over mapped pages past kv_len and over the tail
+    of each row's last live page changes none of its outputs, and the
+    parked row (nothing mapped) writes zeros."""
     B, KH, G, D, page, max_pages = 2, 2, 2, 32, 8, 4
     q, kp, vp, table, lens = _paged_setup(
         rng, B, KH, G, D, page, max_pages, 1 + B * max_pages,
@@ -114,6 +170,83 @@ def test_paged_kernel_ignores_dead_pages(rng):
     got_xla = paged_attention_xla(q, kp2, vp2, table, lens)
     np.testing.assert_allclose(np.asarray(got_xla), np.asarray(want),
                                atol=2e-5)
+
+    # the kernel: 8-token pages, blocks of 32 pages over a 40-page table
+    lens = [5, 256, 300, 40]
+    q, kp, vp, table, lens = _with_room(rng, 4, 2, 6, 128, 8, 40, lens, 20)
+    ops.set_backend("interpret")
+    try:
+        clean = ops.paged_decode_attention(q, kp, vp, table, kv_len=lens)
+        dirty = ops.paged_decode_attention(
+            q, _scribble_dead(table, lens, kp, np.nan),
+            _scribble_dead(table, lens, vp, np.nan), table, kv_len=lens)
+    finally:
+        ops.set_backend("ref")
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    want = ref.paged_attention(q, kp, vp, table, lens)
+    np.testing.assert_allclose(np.asarray(clean)[:-1], np.asarray(want)[:-1],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(clean)[-1], 0.0)
+
+
+@pytest.mark.parametrize("T", [1, 5])
+@pytest.mark.parametrize(
+    "G,page,max_pages,base_len",
+    [
+        # qwen2-1.5b's group; 16-token pages in blocks of 16 over 20:
+        # the furthest draft row ends at 1 + T - 1, 256, 257 and 300
+        pytest.param(6, 16, 20, (1, 252, 253, 296), id="qwen2-G6"),
+        # glm4-9b's group; 8-token pages in blocks of 32 over 40
+        pytest.param(16, 8, 40, (3, 250, 254, 316), id="glm4-G16-page8"),
+    ],
+)
+def test_paged_verify_kernel_matches_oracle(rng, G, page, max_pages,
+                                            base_len, T):
+    """The verify entry walks the same pages with ``T * G`` query rows
+    and per-row causal limits; at ``T == 1`` it is the decode kernel."""
+    B, KH, D = len(base_len), 2, 128
+    q, kp, vp, table, lens = _paged_setup(
+        rng, B, KH, G, D, page, max_pages, 1 + B * max_pages,
+        np.asarray(base_len) + T - 1)
+    q = _rand(rng, (B, T, KH * G, D), jnp.float32)
+    base = jnp.asarray(base_len, jnp.int32)
+    want = ref.paged_attention_mq(q, kp, vp, table, base)
+    ops.set_backend("interpret")
+    try:
+        got = ops.paged_decode_attention_mq(q, kp, vp, table, base_len=base)
+        if T == 1:
+            single = ops.paged_decode_attention(q, kp, vp, table,
+                                                kv_len=base)
+            np.testing.assert_array_equal(np.asarray(got),
+                                          np.asarray(single))
+    finally:
+        ops.set_backend("ref")
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=2e-5)
+
+
+def test_paged_verify_kernel_ignores_dead_pages(rng):
+    """The verify walk at ``T = 5``: NaN past the furthest draft row's
+    limit, in unmapped pages and in the parked row's null page, changes
+    none of its outputs; the parked row writes zeros."""
+    T = 5
+    base = [1, 252, 253, 60]
+    q, kp, vp, table, lens = _with_room(rng, 4, 2, 6, 128, 16, 20, base, 24,
+                                        T=T)
+    hi = lens + T - 1  # the furthest draft row's limit
+    ops.set_backend("interpret")
+    try:
+        clean = ops.paged_decode_attention_mq(q, kp, vp, table, base_len=lens)
+        dirty = ops.paged_decode_attention_mq(
+            q, _scribble_dead(table, hi, kp, np.nan),
+            _scribble_dead(table, hi, vp, np.nan), table, base_len=lens)
+    finally:
+        ops.set_backend("ref")
+    np.testing.assert_array_equal(np.asarray(dirty), np.asarray(clean))
+    want = ref.paged_attention_mq(q, kp, vp, table, lens)
+    np.testing.assert_allclose(np.asarray(clean)[:-1], np.asarray(want)[:-1],
+                               atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(np.asarray(clean)[-1], 0.0)
 
 
 # ===========================================================================
@@ -256,6 +389,36 @@ def test_paged_prefix_pages_shared_not_copied(qwen2_setup):
     done = eng.run()
     toks = {c.uid: c.tokens for c in done}
     assert toks[0] == toks[1]  # identical prompts, identical greedy tails
+
+
+def test_decode_args_count_pages_walked(qwen2_setup):
+    """``serve.decode``'s ``kv_pages_walked``: each active row's pages
+    at the next decode (``kv_len`` = the device cache's ``pos + 1``),
+    rounded up to the paged kernel's block of 32 8-token pages; a
+    parked slot walks nothing."""
+    cfg, model, params = qwen2_setup
+    eng = ServeEngine(model, params, max_batch=3, max_seq=600, eos_id=-1,
+                      engine="paged", page_size=8)
+    assert pages_per_block(8, eng._max_pages) == 32
+    rng = np.random.default_rng(0)
+    for uid, plen in enumerate((254, 9)):
+        prompt = rng.integers(1, cfg.vocab_size, plen).astype(np.int32)
+        eng.submit(Request(uid=uid, prompt=prompt, max_new_tokens=6))
+    walked = []
+    for _ in range(2):
+        eng.step()
+        args = eng._decode_args(1)
+        pos = np.asarray(eng.cache["pos"])
+        kv_len = [int(pos[s]) + 1 for s in range(3) if eng.active[s]]
+        assert sorted(kv_len) == sorted(
+            len(eng.req[s].prompt) + len(eng.emitted[s])
+            for s in range(3) if eng.active[s])
+        assert args["kv_pages_walked"] == sum(
+            32 * -(-n // 256) for n in kv_len)
+        walked.append(args["kv_pages_walked"])
+    # after one step (the prefill's token and one decoded) 254 + 2
+    # tokens fill one block of 256; after two, 254 + 3 need a second
+    assert walked == [32 + 32, 64 + 32]
 
 
 def test_paged_memory_proportional_to_live_tokens(qwen2_setup):

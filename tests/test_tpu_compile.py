@@ -93,6 +93,23 @@ def test_paged_decode_attention_qwen2(one_chip, tpu_backend, page):
              ((KH, P, page, D), BF16), ((B, max_pages), I32), ((B,), I32))
 
 
+@pytest.mark.parametrize("heads,max_pages", [
+    pytest.param(12, 128, id="qwen2-chat"),   # max_seq 2048
+    pytest.param(32, 520, id="glm4-docs"),    # max_seq 8320
+])
+def test_paged_decode_attention_cells(one_chip, tpu_backend, heads,
+                                      max_pages):
+    """The serving cells' decode shapes: 16 slots of 16-token pages,
+    qwen2-1.5b (G=6, 128 table slots) and glm4-9b (32 heads over 2 KV
+    heads, G=16, 520 slots, which the 16-page blocks do not divide)."""
+    B, page = 16, 16
+    P = 1 + B * max_pages
+    _compile(lambda q, kp, vp, t, n: ops.paged_decode_attention(
+                 q, kp, vp, t, kv_len=n),
+             one_chip, ((B, 1, heads, D), BF16), ((KH, P, page, D), BF16),
+             ((KH, P, page, D), BF16), ((B, max_pages), I32), ((B,), I32))
+
+
 def test_paged_verify_attention_qwen2(one_chip, tpu_backend):
     """Speculative verify at spec_k=4: 5 positions x 6 heads per KV
     head = 30 query rows per page walk."""
